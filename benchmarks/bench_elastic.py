@@ -165,7 +165,9 @@ def _clean_reference(clips, *, slots):
 def _collective_bytes() -> tuple:
     """(ring_total, f32_total) wire bytes of one sharded smoke-LM decode
     step at tp=2.  Needs 2 devices — falls back to a subprocess with the
-    host-device-count flag when the parent runs single-device."""
+    host-device-count flag when the parent runs single-device.  The child
+    is pinned to the CPU: a parent that holds a chip must never start a
+    child that reaches for it."""
     import jax
     if len(jax.devices()) >= 2:
         from repro.serve.sharded import lm_decode_collective_bytes
@@ -181,6 +183,7 @@ def _collective_bytes() -> tuple:
         "print('F32', f(tp=2, ring=False)['total'])\n")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env)
     vals = {}

@@ -12,15 +12,14 @@ interconnect instead of the multiplier datapath:
   fleet         replica fleet supervision for elastic sharded serving —
                 routing, replica-loss recovery, rescale (docs/distributed_serving.md)
 
-Importing this package also installs the jax version-compatibility shims
-(``jax.shard_map`` on releases that only ship the experimental API) so model
-code and tests can use the modern spelling uniformly.
+Importing this package also pins the CPU platform for host-mesh dry-runs
+(``compat.install``).
 """
 
 from repro.dist import compat as _compat
 
 _compat.install()
 
-from repro.dist import meshctx  # noqa: E402  (shims must install first)
+from repro.dist import meshctx  # noqa: E402  (the pin must come first)
 
 __all__ = ["meshctx", "compat"]

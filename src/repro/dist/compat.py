@@ -1,11 +1,9 @@
-"""jax cross-version shims.
+"""Platform pinning for host-mesh dry-runs.
 
-The container pins a jax release where ``shard_map`` still lives in
-``jax.experimental.shard_map`` and its replication checker is spelled
-``check_rep``; newer releases expose ``jax.shard_map(..., check_vma=...)``.
-Model code and the test suite use the modern spelling, so installing the
-package aliases the experimental entry point onto ``jax`` and translates the
-keyword.  On a jax that already ships ``jax.shard_map`` this is a no-op.
+A process that forces host-platform devices (``XLA_FLAGS`` with
+``--xla_force_host_platform_device_count``) is by definition a CPU-mesh
+dry-run; :func:`install` pins ``JAX_PLATFORMS=cpu`` for it when the caller
+left the platform unset.
 """
 
 from __future__ import annotations
@@ -15,31 +13,10 @@ import os
 import jax
 
 
-def _shard_map_compat(f=None, *, mesh=None, in_specs=None, out_specs=None,
-                      check_vma=None, check_rep=None, **kwargs):
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    check = True
-    if check_rep is not None:
-        check = check_rep
-    elif check_vma is not None:
-        check = check_vma
-    if f is None:  # used as a decorator factory
-        def deco(fn):
-            return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check, **kwargs)
-        return deco
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check, **kwargs)
-
-
 def install() -> None:
-    if not hasattr(jax, "shard_map"):
-        jax.shard_map = _shard_map_compat
-    # Forcing host-platform devices is by definition a CPU-mesh dry-run; if
-    # the caller didn't pin a platform, pin CPU now.  Otherwise a container
-    # with libtpu installed but no TPU attached stalls for minutes probing
-    # the cloud metadata server before falling back.
+    # Without the pin, a host with libtpu installed but no TPU attached
+    # stalls for minutes probing the cloud metadata server before falling
+    # back to the CPU.
     if ("xla_force_host_platform_device_count" in os.environ.get("XLA_FLAGS", "")
             and not os.environ.get("JAX_PLATFORMS")):
         os.environ["JAX_PLATFORMS"] = "cpu"

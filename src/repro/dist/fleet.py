@@ -51,11 +51,18 @@ from repro.obs.metrics import Registry
 
 
 def fleet_meshes(replicas: int, tp: int = 1) -> list:
-    """One ``(1, tp)`` mesh per replica over disjoint device slices when
-    ``replicas * tp`` local devices exist; otherwise every replica shares
-    the first ``tp`` devices (degenerate but correct — the CI fast suite
-    runs whole fleets on one host CPU device this way)."""
+    """One ``(1, tp)`` mesh per replica over disjoint device slices.
+
+    With fewer than ``replicas * tp`` local devices, CPU hosts let every
+    replica share the first ``tp`` devices (degenerate but correct — the
+    CI fast suite runs whole fleets on one host CPU device this way).  On
+    an accelerator that would stack every replica onto the same chips, so
+    it raises instead."""
     devs = jax.devices()
+    if replicas * tp > len(devs) and devs[0].platform != "cpu":
+        raise ValueError(
+            f"{replicas} replicas x tp={tp} need {replicas * tp} "
+            f"{devs[0].platform} devices, have {len(devs)}")
     tp = min(tp, len(devs))
     meshes = []
     for r in range(replicas):

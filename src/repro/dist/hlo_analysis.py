@@ -117,7 +117,7 @@ _CALLEE_RE = {
 }
 _TRIP_RE = re.compile(r'"known_trip_count"\s*:\s*\{\s*"n"\s*:\s*"?(\d+)"?')
 _CONST_RE = re.compile(r"constant\((\d+)\)")
-_OPERAND_SHAPE_RE = re.compile(r"([a-z0-9]+\[[0-9,]*\])(?:\{[^}]*\})?\s*%")
+_OPERAND_NAME_RE = re.compile(r"\s*%?([\w.\-]+)\s*[,)]")
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 
 
@@ -185,17 +185,18 @@ def _dims(shape: str) -> list[int]:
     return [int(d) for d in m.group(2).split(",")]
 
 
-def _dot_flops(shape: str, rest: str) -> tuple[float, str]:
-    """(flops, accumulator dtype) for one dot instruction line."""
+def _dot_flops(shape: str, rest: str,
+               shapes: dict[str, str]) -> tuple[float, str]:
+    """(flops, accumulator dtype) for one dot instruction line.  The HLO
+    text prints operands by name only (``dot(%a, %b)``), so ``shapes``
+    maps the computation's instruction names to their shapes."""
     out_dims = _dims(shape)
     flops = 2.0
     for d in out_dims:
         flops *= d
     cm = _CONTRACT_RE.search(rest)
-    lhs_shape = None
-    op_shapes = _OPERAND_SHAPE_RE.findall(rest)
-    if op_shapes:
-        lhs_shape = op_shapes[0]
+    m = _OPERAND_NAME_RE.match(rest)
+    lhs_shape = shapes.get(m.group(1)) if m else None
     if cm is not None and lhs_shape is not None and cm.group(1):
         ldims = _dims(lhs_shape)
         for i in (int(x) for x in cm.group(1).split(",")):
@@ -243,13 +244,15 @@ def analyze_hlo(text: str) -> HloReport:
         flops = 0.0
         by_dtype: dict[str, float] = {}
         coll = CollectiveReport()
+        shapes: dict[str, str] = {}
         for line in comps.get(name, ()):
             parsed = _parse_instruction(line)
             if parsed is None:
                 continue
             iname, shape, opcode, rest = parsed
+            shapes[iname] = shape
             if opcode == "dot":
-                f, dt = _dot_flops(shape, rest)
+                f, dt = _dot_flops(shape, rest, shapes)
                 flops += f
                 by_dtype[dt] = by_dtype.get(dt, 0.0) + f
             elif opcode.endswith("-done"):

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.flash_attention import _resolve_interpret
+
 Array = jnp.ndarray
 
 
@@ -47,12 +49,13 @@ def _pr_kernel(pr_ref, a_ref, b_ref, out_ref, *, n: int):
 @functools.partial(jax.jit, static_argnames=("n", "block", "interpret"))
 def pr_multiply(a: Array, b: Array, p: Array | int, r: Array | int,
                 *, n: int = 16, block: int = 2048,
-                interpret: bool = True) -> Array:
+                interpret: bool | None = None) -> Array:
     """Elementwise DyFXU product of int32 operand arrays (n-bit values).
 
     a, b: same shape, total size % block == 0 (callers pad); p, r runtime
     scalars.  N-D operands (e.g. a stacked (taps, L) FIR batch) are flattened
-    for the kernel and restored on return.
+    for the kernel and restored on return.  ``interpret=None`` compiles the
+    kernel on a TPU and interprets it elsewhere.
     """
     shape = a.shape
     assert b.shape == shape, (shape, b.shape)
@@ -78,6 +81,6 @@ def pr_multiply(a: Array, b: Array, p: Array | int, r: Array | int,
             out_specs=pl.BlockSpec((rows, lanes), lambda i, *_: (i, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(a2.shape, jnp.int32),
-        interpret=interpret,
+        interpret=_resolve_interpret(interpret),
     )(pr, a2, b2)
     return out.reshape(shape)

@@ -10,7 +10,10 @@ TPU mapping (VMEM/MXU co-design, the Ch. 9 scratchpad-scheduling insight):
   * tiles (bm, bk) x (bn, bk) -> (bm, bn), multiples of 128 so the MXU
     systolic array is fully utilized and int8 ingestion is 2x bf16 rate;
   * quantization block == bk so each grid step consumes exactly one scale
-    column: scales ride along in VMEM, bk x smaller than the int tiles;
+    column: the whole (bm, K//bk) / (K//bk, bn) scale blocks ride along in
+    VMEM (the chip refuses a (bm, 1) block) and the kernel selects column k;
+  * the perforated tiles are cast back to int8 (lossless: they stay in
+    [-127, 127]) so the MXU runs s8 x s8 -> s32;
   * f32 accumulator tile lives in a VMEM scratch across the K grid walk
     (output tile revisited over k), written back once on the last k step;
   * working set per step: bm*bk + bn*bk int8 + 2*bm*bn f32
@@ -46,19 +49,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quantization import quantize_block
+from repro.kernels.flash_attention import _resolve_interpret
 from repro.kernels.qstore import PackedQWeight, prepack_weight, resolve_block
 
 Array = jnp.ndarray
 
 _ACTS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}
-
-
-def _resolve_interpret(interpret):
-    if interpret is None:
-        from repro.kernels.flash_attention import _resolve_interpret as r
-
-        return r(None)
-    return interpret
 
 
 def _degrade_tile(q: Array, shift: Array) -> Array:
@@ -70,23 +66,42 @@ def _degrade_tile(q: Array, shift: Array) -> Array:
     return jnp.clip(out, -127, 127)
 
 
-def _step_dot(ebits_ref, qx_ref, qw_ref, sx_ref, sw_ref):
+def _degrade_s8(q_ref, shift: Array) -> Array:
+    """Load an int8 operand tile and degrade it to the runtime effective
+    bits.  The degrade runs in int32 lanes and lands back in [-127, 127],
+    so the cast back to int8 is lossless and the MXU sees s8 operands."""
+    return _degrade_tile(q_ref[...].astype(jnp.int32), shift).astype(jnp.int8)
+
+
+def _s8_dot(qx: Array, qw: Array) -> Array:
+    """s8 x s8 -> s32 MXU dot of (bm, bk) by (bn, bk): contract both on k."""
+    return jax.lax.dot_general(qx, qw,
+                               dimension_numbers=(((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _scale_at(s_ref, k, axis: int) -> Array:
+    """Entry ``k`` along ``axis`` of a scale block, the axis kept at size 1:
+    column k of the (rows, n_k) activation scales (axis 1), row k of the
+    transposed (n_k, cols) weight scales (axis 0).  A one-hot
+    select-and-sum: exact (one nonzero term), and it keeps every block the
+    whole scale array along k, which the chip's tiling rules accept where a
+    block of 1 is refused."""
+    s = s_ref[...]
+    hit = jax.lax.broadcasted_iota(jnp.int32, s.shape, axis) == k
+    return jnp.sum(jnp.where(hit, s, 0.0), axis=axis, keepdims=True)
+
+
+def _step_dot(ebits_ref, qx_ref, qw_ref, sx_ref, swT_ref, k):
     """One k-step partial product: degrade both int tiles to the runtime
     effective bits, s8 x s8 -> s32 dot, scale by the block scales."""
     shift = jnp.maximum(8 - ebits_ref[0], 0)
-    qx = _degrade_tile(qx_ref[...].astype(jnp.int32), shift)
-    qw = _degrade_tile(qw_ref[...].astype(jnp.int32), shift)
-    # MXU int8 path: s8 x s8 -> s32 (int32 lanes under interpret mode)
-    acc = jax.lax.dot_general(
-        qx, qw,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
-    scale = sx_ref[...] * sw_ref[...].T          # (bm,1)*(1,bn) -> (bm,bn)
+    acc = _s8_dot(_degrade_s8(qx_ref, shift), _degrade_s8(qw_ref, shift))
+    scale = _scale_at(sx_ref, k, 1) * _scale_at(swT_ref, k, 0)  # (bm, bn)
     return acc.astype(jnp.float32) * scale
 
 
-def _axqmm_kernel(ebits_ref, qx_ref, sx_ref, qw_ref, sw_ref, *rest,
+def _axqmm_kernel(ebits_ref, qx_ref, sx_ref, qw_ref, swT_ref, *rest,
                   n_k: int, has_bias: bool, has_res: bool):
     idx = 0
     bias_ref = rest[idx] if has_bias else None
@@ -100,7 +115,7 @@ def _axqmm_kernel(ebits_ref, qx_ref, sx_ref, qw_ref, sw_ref, *rest,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += _step_dot(ebits_ref, qx_ref, qw_ref, sx_ref, sw_ref)
+    acc_ref[...] += _step_dot(ebits_ref, qx_ref, qw_ref, sx_ref, swT_ref, k)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -114,8 +129,8 @@ def _axqmm_kernel(ebits_ref, qx_ref, sx_ref, qw_ref, sw_ref, *rest,
         out_ref[...] = y
 
 
-def _axqmm_gated_kernel(ebits_ref, qx_ref, sx_ref, qu_ref, su_ref,
-                        qg_ref, sg_ref, out_ref, accu_ref, accg_ref,
+def _axqmm_gated_kernel(ebits_ref, qx_ref, sx_ref, qu_ref, suT_ref,
+                        qg_ref, sgT_ref, out_ref, accu_ref, accg_ref,
                         *, n_k: int, act: str):
     k = pl.program_id(2)
 
@@ -126,16 +141,12 @@ def _axqmm_gated_kernel(ebits_ref, qx_ref, sx_ref, qu_ref, su_ref,
 
     # the x tile is streamed (and degraded) ONCE per step for both GEMMs
     shift = jnp.maximum(8 - ebits_ref[0], 0)
-    qx = _degrade_tile(qx_ref[...].astype(jnp.int32), shift)
-    qu = _degrade_tile(qu_ref[...].astype(jnp.int32), shift)
-    qg = _degrade_tile(qg_ref[...].astype(jnp.int32), shift)
-    dn = (((1,), (1,)), ((), ()))
-    up = jax.lax.dot_general(qx, qu, dimension_numbers=dn,
-                             preferred_element_type=jnp.int32)
-    gt = jax.lax.dot_general(qx, qg, dimension_numbers=dn,
-                             preferred_element_type=jnp.int32)
-    accu_ref[...] += up.astype(jnp.float32) * (sx_ref[...] * su_ref[...].T)
-    accg_ref[...] += gt.astype(jnp.float32) * (sx_ref[...] * sg_ref[...].T)
+    qx = _degrade_s8(qx_ref, shift)
+    up = _s8_dot(qx, _degrade_s8(qu_ref, shift))
+    gt = _s8_dot(qx, _degrade_s8(qg_ref, shift))
+    sx = _scale_at(sx_ref, k, 1)
+    accu_ref[...] += up.astype(jnp.float32) * (sx * _scale_at(suT_ref, k, 0))
+    accg_ref[...] += gt.astype(jnp.float32) * (sx * _scale_at(sgT_ref, k, 0))
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -148,11 +159,11 @@ def _axqmm_gated_kernel(ebits_ref, qx_ref, sx_ref, qu_ref, su_ref,
     jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
 def axqmm_quantized(qx: Array, sx: Array, qwT: Array, sw: Array,
                     ebits: Array | int = 8, *, bm: int = 128, bn: int = 128,
-                    bk: int = 512, interpret: bool = True) -> Array:
+                    bk: int = 512, interpret: bool | None = None) -> Array:
     """qx: (M, K) int8; sx: (M, K//bk) f32; qwT: (N, K) int8;
     sw: (N, K//bk) f32; ebits: runtime scalar.  Returns (M, N) f32."""
-    return _axqmm_call(qx, sx, qwT, sw, ebits, None, None,
-                       bm=bm, bn=bn, bk=bk, interpret=interpret)
+    return _axqmm_call(qx, sx, qwT, sw, ebits, None, None, bm=bm, bn=bn,
+                       bk=bk, interpret=_resolve_interpret(interpret))
 
 
 def _axqmm_call(qx, sx, qwT, sw, ebits, bias, residual, *, bm, bn, bk,
@@ -164,12 +175,12 @@ def _axqmm_call(qx, sx, qwT, sw, ebits, bias, residual, *, bm, bn, bk,
     ebits_arr = jnp.asarray(ebits, jnp.int32).reshape(1)
     grid = (M // bm, N // bn, n_k)
     in_specs = [
-        pl.BlockSpec((bm, bk), lambda i, j, k, *prefetch: (i, k)),   # qx
-        pl.BlockSpec((bm, 1), lambda i, j, k, *prefetch: (i, k)),    # sx
-        pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),   # qwT
-        pl.BlockSpec((bn, 1), lambda i, j, k, *prefetch: (j, k)),    # sw
+        pl.BlockSpec((bm, bk), lambda i, j, k, *prefetch: (i, k)),    # qx
+        pl.BlockSpec((bm, n_k), lambda i, j, k, *prefetch: (i, 0)),   # sx
+        pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),    # qwT
+        pl.BlockSpec((n_k, bn), lambda i, j, k, *prefetch: (0, j)),   # sw.T
     ]
-    args = [qx, sx, qwT, sw]
+    args = [qx, sx, qwT, sw.T]
     if bias is not None:
         in_specs.append(pl.BlockSpec((1, bn), lambda i, j, k, *prefetch: (0, j)))
         args.append(bias.reshape(1, N).astype(jnp.float32))
@@ -205,12 +216,12 @@ def _axqmm_gated_call(qx, sx, qu, su, qg, sg, ebits, *, act, bm, bn, bk,
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((bm, bk), lambda i, j, k, *prefetch: (i, k)),  # qx
-                pl.BlockSpec((bm, 1), lambda i, j, k, *prefetch: (i, k)),   # sx
-                pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),  # qu
-                pl.BlockSpec((bn, 1), lambda i, j, k, *prefetch: (j, k)),   # su
-                pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),  # qg
-                pl.BlockSpec((bn, 1), lambda i, j, k, *prefetch: (j, k)),   # sg
+                pl.BlockSpec((bm, bk), lambda i, j, k, *prefetch: (i, k)),   # qx
+                pl.BlockSpec((bm, n_k), lambda i, j, k, *prefetch: (i, 0)),  # sx
+                pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),   # qu
+                pl.BlockSpec((n_k, bn), lambda i, j, k, *prefetch: (0, j)),  # su.T
+                pl.BlockSpec((bn, bk), lambda i, j, k, *prefetch: (j, k)),   # qg
+                pl.BlockSpec((n_k, bn), lambda i, j, k, *prefetch: (0, j)),  # sg.T
             ],
             out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *prefetch: (i, j)),
             scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
@@ -218,7 +229,7 @@ def _axqmm_gated_call(qx, sx, qu, su, qg, sg, ebits, *, act, bm, bn, bk,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
-    )(ebits_arr, qx, sx, qu, su, qg, sg)
+    )(ebits_arr, qx, sx, qu, su.T, qg, sg.T)
 
 
 def quantize_for_axqmm(x: Array, bk: int = 512):
@@ -230,6 +241,9 @@ def quantize_for_axqmm(x: Array, bk: int = 512):
 
 
 def _tile(dim: int) -> int:
+    """Row tile for M.  N always tiles at 128 (padded up): the output and
+    weight-scale blocks put N on the lane axis, where the chip accepts only
+    multiples of 128 short of the whole array."""
     return 128 if dim % 128 == 0 else (64 if dim % 64 == 0 else 8)
 
 
@@ -257,7 +271,7 @@ def axqmm_packed(x: Array, pw: PackedQWeight, ebits: Array | int = 8, *,
     assert pw.k == K, (pw.k, K)
     qx, sx = quantize_for_axqmm(x, bk)
     qw, sw = pw.qw, pw.scales
-    bm, bn = _tile(M), _tile(N)
+    bm, bn = _tile(M), 128
     Mp = -(-M // bm) * bm
     Np = -(-N // bn) * bn
     if Mp != M:
@@ -289,7 +303,7 @@ def axqmm_gated_packed(x: Array, pw_up: PackedQWeight, pw_gate: PackedQWeight,
     qx, sx = quantize_for_axqmm(x, bk)
     qu, su = pw_up.qw, pw_up.scales
     qg, sg = pw_gate.qw, pw_gate.scales
-    bm, bn = _tile(M), _tile(N)
+    bm, bn = _tile(M), 128
     Mp = -(-M // bm) * bm
     Np = -(-N // bn) * bn
     if Mp != M:

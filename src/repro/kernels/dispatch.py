@@ -174,25 +174,64 @@ def inject_fault(x, fault):
 # ---------------------------------------------------------------------------
 
 
+def _head_sharded(fn, *args):
+    """Run ``fn(*args)`` per device when a mesh wider than one device is
+    active.
+
+    The chip's compiler cannot partition a Mosaic kernel, so the attention
+    kernels run under ``shard_map``.  Every argument and result of rank 3 or
+    more carries its head axis at dim 2 and is split over ``"model"``; it and
+    every (B,) vector split the batch over the data axes where it divides
+    them; scalars are replicated.  Attention is independent per (slot,
+    head), so the per-device calls compute exactly what one device would."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.dist import meshctx
+
+    mesh = meshctx.get_mesh()
+    if mesh.size == 1:
+        return fn(*args)
+    bd = meshctx.batch_axes(mesh)
+    width = int(np.prod([mesh.shape[a] for a in bd])) if bd else 1
+    b = tuple(bd) if bd and args[0].shape[0] % width == 0 else None
+
+    def spec(a):
+        nd = jnp.ndim(a)
+        if nd == 0:
+            return P()
+        if nd == 1:
+            return P(b)
+        return P(b, None, "model", *([None] * (nd - 3)))
+
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=jax.tree.map(spec, args),
+        out_specs=jax.tree.map(spec, jax.eval_shape(fn, *args)),
+        check_vma=False)(*args)
+
+
 def prefill_attention(q: Array, k: Array, v: Array, *, causal: bool,
                       window: Optional[int] = None) -> Array:
     """Full-sequence GQA attention, model layout: q (B, S, H, D),
     k/v (B, S, KVr, D) -> (B, S, H, D)."""
     from repro.models import attention as attn  # lazy: kernels<->models layering
 
-    B, S, H, D = q.shape
-    qualifies = use_pallas() and S > 1 and (causal or window is None)
+    qualifies = use_pallas() and q.shape[1] > 1 and (causal or window is None)
     _record_route("prefill", "pallas" if qualifies else "xla")
     if not qualifies:
         return attn.attn_blockwise(q, k, v, causal=causal, window=window)
-    kf = attn.repeat_kv(k, H)
-    vf = attn.repeat_kv(v, H)
 
-    def flat(x):
-        return x.transpose(0, 2, 1, 3).reshape(B * H, S, D)
+    def run(q, k, v):
+        b, s, h, d = q.shape
+        kf = attn.repeat_kv(k, h)
+        vf = attn.repeat_kv(v, h)
 
-    o = flash_attention_vjp(flat(q), flat(kf), flat(vf), causal, window)
-    return o.reshape(B, H, S, D).transpose(0, 2, 1, 3)
+        def flat(x):
+            return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+        o = flash_attention_vjp(flat(q), flat(kf), flat(vf), causal, window)
+        return o.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+
+    return _head_sharded(run, q, k, v)
 
 
 def decode_attention(q1: Array, knew: Array, vnew: Array, cache, *,
@@ -209,8 +248,12 @@ def decode_attention(q1: Array, knew: Array, vnew: Array, cache, *,
 
     if use_pallas():
         _record_route("decode", "pallas")
-        return decode_attn_flash(q1, knew, vnew, cache, window=window,
-                                 active=active, degree=degree)
+
+        def run(q1, knew, vnew, cache, active, degree):
+            return decode_attn_flash(q1, knew, vnew, cache, window=window,
+                                     active=active, degree=degree)
+
+        return _head_sharded(run, q1, knew, vnew, cache, active, degree)
     _record_route("decode", "xla")
     if isinstance(cache, attn.QuantKVCache):
         return attn.decode_attn_quant(q1, knew, vnew, cache, window=window)

@@ -80,7 +80,7 @@ def pr_multiply_ref(a: Array, b: Array, p, r, *, n: int = 16) -> Array:
 
 
 def pr_product(a: Array, b: Array, p, r, *, n: int = 16,
-               backend: str = "xla", interpret: bool = True) -> Array:
+               backend: str = "xla", interpret: bool | None = None) -> Array:
     """One elementwise PR product through the selected backend.  Pallas route:
     flatten + zero-pad to the kernel's block multiple (zeros multiply to
     zeros, so padding never pollutes); jnp route: the bit-identical ref."""
@@ -100,7 +100,7 @@ def pr_product(a: Array, b: Array, p, r, *, n: int = 16,
 
 
 def fir_valid(sig, taps, p, r, *, n: int = 16, backend: str = "xla",
-              interpret: bool = True) -> np.ndarray:
+              interpret: bool | None = None) -> np.ndarray:
     """Valid-mode batched FIR (the Ch. 7 Tables 7.1/7.2 bench layout):
     ``y[j] = sum_i taps[i] * sig[i + j]`` for ``j < len(sig) - len(taps)``.
 
@@ -120,7 +120,7 @@ def fir_valid(sig, taps, p, r, *, n: int = 16, backend: str = "xla",
 
 def fir_frames(frames: Array, tail: Array, taps: Array, p, r, *, n: int = 16,
                shift: int = 0, backend: str = "xla",
-               interpret: bool = True):
+               interpret: bool | None = None):
     """Streaming FIR over one frame batch (jit-safe; the serve-engine step).
 
     frames (B, L) int32 samples, tail (B, T-1) the previous frame's carried
@@ -144,7 +144,7 @@ def fir_frames(frames: Array, tail: Array, taps: Array, p, r, *, n: int = 16,
 
 def conv2d_pr(img: Array, kern: Array, p, r, *, n: int = 16, shift: int = 0,
               pad: str = "zero", backend: str = "xla",
-              interpret: bool = True) -> Array:
+              interpret: bool | None = None) -> Array:
     """Same-size 2D correlation through the PR datapath (jit-safe).
 
     img (B, H, W) int32 pixels, kern (kh, kw) int32 weights with l1 norm <=

@@ -3,9 +3,11 @@
 Replaces the pure-jnp ``decode_attn`` / ``decode_attn_quant`` full-``T_max``
 einsum in the engine's fused step (kernels/dispatch.py routes the call).
 
-Grid (B, KVr, n_t): t innermost walks the slot's cache region in ``bt``-sized
-tiles with online-softmax scratch per (slot, kv-head); the q block is the
-whole GQA group (G, D), so grouped query heads share each loaded kv tile.
+Grid (B, n_t): t walks the slot's cache region in ``bt``-sized tiles; each
+tile spans every kv head, (1, bt, KVr, D), and the kernel loops over the
+heads with online-softmax scratch per (kv-head, group).  The q block is the
+slot's whole (KVr, G, D) query set, so grouped query heads share each loaded
+kv tile.
 Tiles that start beyond the slot's valid length are *skipped at runtime*
 (``pl.when`` on the scalar-prefetched length — the dissertation's
 computation-skipping pillar keyed on per-slot serving state, not a static
@@ -57,49 +59,62 @@ def _tiles(T: int, bt: int) -> tuple[int, int]:
     return bt, -(-T // bt)
 
 
-def _online_block(s, v, acc_ref, m_ref, l_ref):
-    """One online-softmax accumulation step; s (G, bt) pre-masked."""
-    m_prev = m_ref[...]
+def _online_block(s, v, acc_ref, m_ref, l_ref, h: int):
+    """One online-softmax accumulation step for kv head ``h``; s (G, bt)
+    pre-masked."""
+    m_prev = m_ref[h]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.where(s > 0.5 * NEG_INF, jnp.exp(s - m_new), 0.0)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+    l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    m_ref[h] = m_new
+    acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+
+def _init_state(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
 
 def _finish(o_ref, acc_ref, l_ref, active_ref, b):
     act = (active_ref[b] > 0).astype(jnp.float32)
-    o_ref[0, 0] = (act * acc_ref[...] /
-                   jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = (act * acc_ref[...] /
+                jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+
+
+def _head_scores(q_ref, k, h: int, t, bt: int, nv, scale: float):
+    """Masked scores (G, bt) of kv head ``h``'s query group against one
+    cache tile; also returns the (bt, 1) valid-row mask for v."""
+    q = q_ref[0, h].astype(jnp.float32) * scale                   # (G, D)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # (G, bt)
+    cols = t * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+    rows = t * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
+    return jnp.where(cols < nv, s, NEG_INF), rows < nv
 
 
 def _decode_kernel(nvalid_ref, active_ref, q_ref, k_ref, v_ref, o_ref,
-                   acc_ref, m_ref, l_ref, *, n_t: int, bt: int, scale: float):
-    b, t = pl.program_id(0), pl.program_id(2)
+                   acc_ref, m_ref, l_ref, *, n_t: int, bt: int, kvr: int,
+                   scale: float):
+    b, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     nv = nvalid_ref[b]
 
     @pl.when(t * bt < nv)          # runtime skip: tile wholly past the length
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bt, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (G, bt)
-        cols = t * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-        s = jnp.where(cols < nv, s, NEG_INF)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        # sanitize past-length rows: a ragged final tile reads out of bounds
-        # (undefined lanes) and 0 * NaN would poison the p @ v accumulation
-        v = jnp.where(cols.reshape(bt, 1) < nv, v, 0.0)
-        _online_block(s, v, acc_ref, m_ref, l_ref)
+        for h in range(kvr):       # every kv head of the tile, one DMA
+            k = k_ref[0, :, h, :].astype(jnp.float32)             # (bt, D)
+            s, valid = _head_scores(q_ref, k, h, t, bt, nv, scale)
+            # sanitize past-length rows: a ragged final tile reads out of
+            # bounds (undefined lanes) and 0 * NaN would poison p @ v
+            v = jnp.where(valid, v_ref[0, :, h, :].astype(jnp.float32), 0.0)
+            _online_block(s, v, acc_ref, m_ref, l_ref, h)
 
     @pl.when(t == n_t - 1)
     def _done():
@@ -109,36 +124,53 @@ def _decode_kernel(nvalid_ref, active_ref, q_ref, k_ref, v_ref, o_ref,
 def _decode_kernel_quant(ebits_ref, nvalid_ref, active_ref, q_ref,
                          k_ref, ks_ref, v_ref, vs_ref, o_ref,
                          acc_ref, m_ref, l_ref, *, n_t: int, bt: int,
-                         scale: float):
-    b, t = pl.program_id(0), pl.program_id(2)
+                         kvr: int, scale: float):
+    b, t = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        _init_state(acc_ref, m_ref, l_ref)
 
     nv = nvalid_ref[b]
 
     @pl.when(t * bt < nv)
     def _compute():
         shift = jnp.maximum(8 - ebits_ref[0], 0)
-        q = q_ref[0, 0].astype(jnp.float32) * scale                  # (G, D)
-        kq = _degrade_tile(k_ref[0, :, 0, :].astype(jnp.int32), shift)
-        k = kq.astype(jnp.float32) * ks_ref[0, :, 0][:, None]        # (bt, D)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        cols = t * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
-        s = jnp.where(cols < nv, s, NEG_INF)
-        vq = _degrade_tile(v_ref[0, :, 0, :].astype(jnp.int32), shift)
-        v = vq.astype(jnp.float32) * vs_ref[0, :, 0][:, None]
-        # sanitize past-length rows (ragged final tile: undefined lanes)
-        v = jnp.where(cols.reshape(bt, 1) < nv, v, 0.0)
-        _online_block(s, v, acc_ref, m_ref, l_ref)
+        ks, vs = ks_ref[0], vs_ref[0]                             # (bt, KVr)
+        for h in range(kvr):
+            kq = _degrade_tile(k_ref[0, :, h, :].astype(jnp.int32), shift)
+            k = kq.astype(jnp.float32) * ks[:, h:h + 1]           # (bt, D)
+            s, valid = _head_scores(q_ref, k, h, t, bt, nv, scale)
+            vq = _degrade_tile(v_ref[0, :, h, :].astype(jnp.int32), shift)
+            v = vq.astype(jnp.float32) * vs[:, h:h + 1]
+            # sanitize past-length rows (ragged final tile: undefined lanes)
+            v = jnp.where(valid, v, 0.0)
+            _online_block(s, v, acc_ref, m_ref, l_ref, h)
 
     @pl.when(t == n_t - 1)
     def _done():
         _finish(o_ref, acc_ref, l_ref, active_ref, b)
+
+
+def _grid_spec(n_prefetch: int, B: int, n_t: int, bt: int, KVr: int,
+               G: int, D: int, *, quant: bool):
+    """Grid (B, n_t): each step DMAs one cache tile across ALL kv heads,
+    (1, bt, KVr, D) — the chip's tiling rules refuse a block of 1 against
+    the KVr axis, which sits second-minor in the (B, T, KVr, D) cache."""
+    kv = pl.BlockSpec((1, bt, KVr, D), lambda b, t, *pf: (b, t, 0, 0))
+    sc = pl.BlockSpec((1, bt, KVr), lambda b, t, *pf: (b, t, 0))
+    q = pl.BlockSpec((1, KVr, G, D), lambda b, t, *pf: (b, 0, 0, 0))
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=n_prefetch,
+        grid=(B, n_t),
+        in_specs=[q, kv, sc, kv, sc] if quant else [q, kv, kv],
+        out_specs=pl.BlockSpec((1, KVr, G, D), lambda b, t, *pf: (b, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((KVr, G, D), jnp.float32),
+            pltpu.VMEM((KVr, G, 1), jnp.float32),
+            pltpu.VMEM((KVr, G, 1), jnp.float32),
+        ],
+    )
 
 
 @functools.partial(jax.jit, static_argnames=("bt", "interpret"))
@@ -151,26 +183,11 @@ def flash_decode(qg: Array, k: Array, v: Array, nvalid: Array, active: Array,
     B, KVr, G, D = qg.shape
     T = k.shape[1]
     bt, n_t = _tiles(T, bt)
-    kern = functools.partial(_decode_kernel, n_t=n_t, bt=bt,
+    kern = functools.partial(_decode_kernel, n_t=n_t, bt=bt, kvr=KVr,
                              scale=1.0 / math.sqrt(D))
     return pl.pallas_call(
         kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, KVr, n_t),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, t, *pf: (b, h, 0, 0)),
-                pl.BlockSpec((1, bt, 1, D), lambda b, h, t, *pf: (b, t, h, 0)),
-                pl.BlockSpec((1, bt, 1, D), lambda b, h, t, *pf: (b, t, h, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, t, *pf: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, D), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
+        grid_spec=_grid_spec(2, B, n_t, bt, KVr, G, D, quant=False),
         out_shape=jax.ShapeDtypeStruct((B, KVr, G, D), jnp.float32),
         interpret=interpret,
     )(nvalid.astype(jnp.int32), active.astype(jnp.int32), qg, k, v)
@@ -187,28 +204,11 @@ def flash_decode_quant(qg: Array, k: Array, ks: Array, v: Array, vs: Array,
     B, KVr, G, D = qg.shape
     T = k.shape[1]
     bt, n_t = _tiles(T, bt)
-    kern = functools.partial(_decode_kernel_quant, n_t=n_t, bt=bt,
+    kern = functools.partial(_decode_kernel_quant, n_t=n_t, bt=bt, kvr=KVr,
                              scale=1.0 / math.sqrt(D))
     return pl.pallas_call(
         kern,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(B, KVr, n_t),
-            in_specs=[
-                pl.BlockSpec((1, 1, G, D), lambda b, h, t, *pf: (b, h, 0, 0)),
-                pl.BlockSpec((1, bt, 1, D), lambda b, h, t, *pf: (b, t, h, 0)),
-                pl.BlockSpec((1, bt, 1), lambda b, h, t, *pf: (b, t, h)),
-                pl.BlockSpec((1, bt, 1, D), lambda b, h, t, *pf: (b, t, h, 0)),
-                pl.BlockSpec((1, bt, 1), lambda b, h, t, *pf: (b, t, h)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, G, D),
-                                   lambda b, h, t, *pf: (b, h, 0, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((G, D), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-                pltpu.VMEM((G, 1), jnp.float32),
-            ],
-        ),
+        grid_spec=_grid_spec(3, B, n_t, bt, KVr, G, D, quant=True),
         out_shape=jax.ShapeDtypeStruct((B, KVr, G, D), jnp.float32),
         interpret=interpret,
     )(jnp.asarray(ebits, jnp.int32).reshape(1), nvalid.astype(jnp.int32),

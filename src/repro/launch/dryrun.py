@@ -139,8 +139,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax: one dict per executable
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     rep = analyze_hlo(hlo)
     n_total, n_active = cfg.param_count()

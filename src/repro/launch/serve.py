@@ -42,6 +42,7 @@ from repro.core.approx import policy_from_flag
 from repro.core.dynamic import QoSController
 from repro.dist import meshctx
 from repro.kernels import dispatch as kdispatch
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -318,6 +319,7 @@ def _serve_fleet(args) -> None:
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="lm", choices=("lm", "stream"),
                     help="what to serve: lm (token decode, default) or "

@@ -17,6 +17,7 @@ from repro.core.dynamic import QoSController
 from repro.data.pipeline import make_pipeline
 from repro.dist import meshctx
 from repro.kernels import dispatch as kdispatch
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
@@ -25,6 +26,7 @@ from repro.train.trainer import Trainer, TrainerConfig
 
 
 def main() -> None:
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b-smoke")
     ap.add_argument("--steps", type=int, default=100)

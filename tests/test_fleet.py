@@ -123,6 +123,29 @@ def test_fleet_meshes_shapes_and_sharing():
         assert m.devices.shape == (1, 1)
 
 
+def test_fleet_meshes_refuses_to_stack_replicas_off_cpu(monkeypatch):
+    """On an accelerator, too few devices raise instead of stacking every
+    replica onto the same chips; the CPU keeps the shared form."""
+    import jax
+
+    from repro.dist import fleet
+
+    class _Dev:
+        def __init__(self, platform):
+            self.platform = platform
+
+    monkeypatch.setattr(fleet.jax, "devices", lambda: [_Dev("tpu")])
+    with pytest.raises(ValueError, match="need 2 tpu devices, have 1"):
+        fleet_meshes(2, tp=1)
+    monkeypatch.setattr(fleet.jax, "devices",
+                        lambda: [_Dev("tpu") for _ in range(2)])
+    with pytest.raises(ValueError, match="need 4 tpu devices, have 2"):
+        fleet_meshes(2, tp=2)
+    monkeypatch.undo()
+    assert jax.devices()[0].platform == "cpu"
+    assert len(fleet_meshes(4, tp=1)) == 4
+
+
 def test_routing_is_least_loaded_then_lowest_rid():
     sup = _fleet(3)
     # empty fleet: ties break to replica 0, then spread round-robin-ish

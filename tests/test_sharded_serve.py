@@ -170,3 +170,46 @@ names = [n for _, n, _ in sup.resil_log]
 assert "replica_lost" in names and "rescale" in names
 print("SHARDED_OK")
 """)
+
+
+@pytest.mark.slow
+def test_sharded_pallas_attention_matches_one_device():
+    """The chip's compiler cannot partition a Mosaic kernel, so at tp > 1
+    the attention kernels run under shard_map (kernels/dispatch.py).
+    Per-device calls compute what one device would: greedy decode on a
+    (data 2, model 2) mesh equals the single-device engine, through both
+    the bucketed prefill (one packed row: batch replicated over data) and
+    the decode step (slots sharded over data)."""
+    _run(r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from repro.configs import get_config
+from repro.dist import meshctx
+from repro.kernels import dispatch
+from repro.models import build_model
+from repro.serve.admission import AdmissionConfig
+from repro.serve.sharded import ShardedServeEngine
+from repro.serve.lm import ServeEngine
+
+dispatch.set_backend("pallas")
+cfg = get_config("tinyllama-1.1b-smoke")
+model = build_model(cfg)
+tp = 2
+mesh = meshctx.make_mesh((2, tp), ("data", "model"))
+params = model.init(jax.random.PRNGKey(0), tp=tp)
+prompts = [list(range(1, 12)), [7, 8, 9, 10]]
+adm = AdmissionConfig(buckets=(8, 16))
+eng = ShardedServeEngine(model, params, mesh=mesh, slots=2, max_len=32,
+                         admission=adm)
+reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+eng.run_until_drained()
+assert dispatch.last_route["decode"] == "pallas"
+assert dispatch.last_route["prefill"] == "pallas"
+ref = ServeEngine(model, params, slots=2, max_len=32, tp=tp, admission=adm)
+rrefs = [ref.submit(p, max_new_tokens=6) for p in prompts]
+ref.run_until_drained()
+assert [r.out for r in reqs] == [r.out for r in rrefs], "pallas tp diverged"
+assert all(r.status == "ok" and len(r.out) == 6 for r in reqs)
+print("SHARDED_OK")
+""")
