@@ -34,6 +34,10 @@ VMEM:
     (quantized and degraded once per step), keep two accumulators, and apply
     the gate in-VMEM — one HBM roundtrip instead of three.
 
+Both ``pallas_call``s carry a name (``axqmm``, ``axqmm_gated``), which the
+compiled program gives their custom-call ops (``axqmm.7``): a profiler
+trace lists them by it, not by whatever encloses them (``closed_call.41``).
+
 Validated against core.quantization.qmm_packed_ref / qmm_gated_packed_ref
 (pure-jnp oracles) in interpret mode on CPU (tests/test_kernels.py,
 tests/test_qstore.py).
@@ -199,6 +203,7 @@ def _axqmm_call(qx, sx, qwT, sw, ebits, bias, residual, *, bm, bn, bk,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
+        name="axqmm",
     )(ebits_arr, *args)
 
 
@@ -229,6 +234,7 @@ def _axqmm_gated_call(qx, sx, qu, su, qg, sg, ebits, *, act, bm, bn, bk,
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         interpret=interpret,
+        name="axqmm_gated",
     )(ebits_arr, qx, sx, qu, su.T, qg, sg.T)
 
 

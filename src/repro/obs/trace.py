@@ -2,17 +2,26 @@
 
 The runtime-adjustable approximation scheme is only trustworthy if the
 system can *show* which degree served which request and what it cost
-(DESIGN.md §11).  This tracer is the zero-dependency substrate: bounded
-ring buffers of span / instant / counter events, nestable via context
-manager, exportable as Chrome ``trace_event`` JSON — the file loads
-directly in ``chrome://tracing`` / Perfetto.
+(DESIGN.md §11).  This tracer is the substrate, on the stdlib and jax's
+profiler alone: bounded ring buffers of span / instant / counter events,
+nestable via context manager, exportable as Chrome ``trace_event`` JSON —
+the file loads directly in ``chrome://tracing`` / Perfetto.
 
 Contract:
 
-  * **disabled is free** — the global tracer starts disabled; ``span()``
-    returns a shared no-op context manager and ``event()`` returns
-    immediately, so instrumented hot paths (the serve tick, the train
-    step) pay one predicate per call site.
+  * **one tracer, two sinks** — every ``span()`` also opens a
+    ``jax.profiler.TraceAnnotation`` named ``<track>.<name>`` with the
+    span's args as its kwargs (a ``prefill`` span on track ``engine``
+    lands in a profiler trace as ``engine.prefill``), so the program's
+    own spans sit on the profiler's clock beside the device's ops.  The
+    profiler session is the switch: outside one, no annotation is made.
+    ``event()`` and ``counter()`` go to the ring buffer only.
+  * **disabled is nearly free** — the global tracer starts disabled:
+    ``event()`` and ``counter()`` return after one predicate, and
+    ``span()`` after that predicate and one static profiler check
+    (``TraceAnnotation.is_enabled()``), handing out a shared no-op context
+    manager; the call site still builds its kwargs.  While a profiler
+    session records, a span costs one annotation, ring buffer or not.
   * **bounded** — events land in a ``deque(maxlen=capacity)``; overflow
     evicts the oldest and increments ``dropped`` (long-lived engines never
     leak).
@@ -28,6 +37,9 @@ Usage::
         ...
     trace.event("qos_rung", degrees=[8, 7, 6])
     trace.get_tracer().write("trace.json")      # open in chrome://tracing
+
+Args known only when a span closes are added with ``set_metadata(**args)``
+on the object the ``with`` statement yields; both sinks take them.
 """
 
 from __future__ import annotations
@@ -38,6 +50,8 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "get_tracer", "set_tracer", "enable", "disable",
            "span", "event", "counter"]
@@ -54,14 +68,25 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    """One live span: records a Chrome complete event ('X') on exit."""
+def _annotation(name: str, track: str, args: dict):
+    """The profiler's twin of a span, or None outside a profiler session."""
+    if not TraceAnnotation.is_enabled():
+        return None
+    return TraceAnnotation(f"{track}.{name}", **args)
 
-    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0")
+
+class _Span:
+    """One live span: records a Chrome complete event ('X') on exit, and
+    an annotation while a profiler session records."""
+
+    __slots__ = ("_tracer", "_name", "_track", "_args", "_t0", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str, args: dict):
         self._tracer = tracer
@@ -69,10 +94,19 @@ class _Span:
         self._track = track
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = _annotation(self._name, self._track, self._args)
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def set_metadata(self, **args) -> None:
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
@@ -82,6 +116,8 @@ class _Span:
             "pid": self._tracer.pid, "tid": self._tracer._tid(self._track),
             "cat": "repro", "args": self._args,
         })
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
 
 
@@ -143,10 +179,13 @@ class Tracer:
             self._events.append(ev)
 
     def span(self, name: str, track: str = "main", **args):
-        """Context manager timing a nested region; a no-op when disabled."""
-        if not self.enabled:
-            return _NULL_SPAN
-        return _Span(self, name, track, args)
+        """Context manager timing a nested region: a Chrome event while
+        enabled, a profiler annotation while a profiler session records, a
+        no-op otherwise."""
+        if self.enabled:
+            return _Span(self, name, track, args)
+        ann = _annotation(name, track, args)
+        return _NULL_SPAN if ann is None else ann
 
     def event(self, name: str, track: str = "main", **args) -> None:
         """Instant event ('i') — a point-in-time marker with payload."""

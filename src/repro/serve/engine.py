@@ -136,10 +136,10 @@ class ServeCore:
     admission/ingest, per-tick step, first emission, completion, QoS rung
     transitions (with the per-site degree vector attached) — is traced
     through ``tracer`` (the process-global :mod:`repro.obs.trace` tracer
-    by default; free when disabled) under the *workload's* vocabulary, and
-    every counter lives in ``stats.registry`` (a fresh
-    :class:`repro.obs.metrics.Registry`, or pass ``registry=`` to co-export
-    with the dispatch counters).  ``quality_every=N`` samples the
+    by default, whose spans also reach any JAX profiler session) under the
+    *workload's* vocabulary, and every counter lives in ``stats.registry``
+    (a fresh :class:`repro.obs.metrics.Registry`, or pass ``registry=`` to
+    co-export with the dispatch counters).  ``quality_every=N`` samples the
     live-vs-exact output error every N ticks into a per-rung histogram
     (``obs/quality.py``) through the workload's quality tap.
     """
@@ -339,11 +339,13 @@ class ServeCore:
         req.t_admitted = self._clock()
         wl = self.workload
         with self._tracer.span(wl.admit_span, track="engine", rid=req.rid,
-                               slot=slot,
-                               **{wl.payload_arg: req.payload_units}):
+                               slot=slot, requests=1,
+                               waited_ms=req.queue_time * 1e3,
+                               **{wl.payload_arg: req.payload_units}) as sp:
             self.state, ingested = wl.admit(self.params, self.state,
                                             self._feed, slot, req,
                                             self._degree)
+            sp.set_metadata(**self._shape_args(int(ingested)))
         req.admitted_units = int(ingested)
         if req.admitted_units > 0:
             self.stats.c_admit_units.inc(req.admitted_units)
@@ -359,11 +361,17 @@ class ServeCore:
     def _chunk_call(self, slot: int, req: Request) -> None:
         """One chunked-prefill device call advancing ``req``'s admission."""
         wl = self.workload
+        # a request counts in the call that starts its admission alone
+        first = req.cursor == 0
         with self._tracer.span(wl.admit_span, track="engine", rid=req.rid,
-                               slot=slot, chunk=True, cursor=req.cursor):
+                               slot=slot, chunk=True, cursor=req.cursor,
+                               requests=int(first),
+                               waited_ms=req.queue_time * 1e3 if first
+                               else 0.0) as sp:
             self.state, n = wl.admit_chunk(self.params, self.state,
                                            self._feed, slot, req,
                                            self._degree)
+            sp.set_metadata(**self._shape_args(int(n)))
         req.admitted_units += int(n)
         if n > 0:
             self.stats.c_admit_units.inc(int(n))
@@ -379,24 +387,35 @@ class ServeCore:
         wl = self.workload
         with self._tracer.span(wl.admit_span, track="engine",
                                rid=pairs[0][1].rid, slot=pairs[0][0],
-                               packed=len(pairs)):
+                               packed=len(pairs), requests=len(pairs),
+                               waited_ms=sum(r.queue_time
+                                             for _, r in pairs) * 1e3) as sp:
             self.state, ingested = wl.admit_batch(self.params, self.state,
                                                   self._feed, pairs,
                                                   self._degree)
-        total = 0
+            total = sum(int(n) for n in ingested)
+            sp.set_metadata(**self._shape_args(total))
         for (_, req), n in zip(pairs, ingested):
             req.admitted_units = int(n)
-            total += int(n)
         if total > 0:
             self.stats.c_admit_units.inc(total)
         self.stats.c_admit_calls.inc()
         if len(pairs) > 1:
             self.stats.c_packed_rows.inc(len(pairs))
-        bucket = getattr(wl, "last_admit_bucket", None)
-        if bucket is not None:
-            self.stats.c_admit_bucket.labels(bucket=str(bucket)).inc()
+        if wl.last_admit_shape is not None:
+            self.stats.c_admit_bucket.labels(
+                bucket=str(wl.last_admit_shape[0])).inc()
         if wl.admit_site:
             self._count_route(wl.admit_site)
+
+    def _shape_args(self, ingested: int) -> dict:
+        """Trace args of one admission call, known once it returns: the
+        payload units it ingested (``tokens``), the positions its
+        executable computes over all rows (``padded``) and the payload
+        length it was built for (``bucket``)."""
+        shape = self.workload.last_admit_shape
+        bucket, padded = shape if shape is not None else (ingested, ingested)
+        return {"tokens": ingested, "padded": padded, "bucket": bucket}
 
     def _admit_pipeline(self, now: float) -> None:
         """Bucketed/packed/chunked admission: first advance mid-admission
@@ -658,25 +677,46 @@ class ServeCore:
         """One engine iteration: admit queued requests into free slots
         (fused ingest per admission), update the QoS degree, run ONE fused
         step over all slots, and harvest emissions / finished requests.
-        Returns the number of active slots (0 = idle)."""
-        wl = self.workload
+        Returns the number of active slots (0 = idle).
+
+        Its phases are sibling spans under ``tick``: ``admit``, the step's
+        ``{step_span}_tick`` (holding ``dispatch`` and ``sync``) and
+        ``harvest``, so a profiler trace charges each stretch the device
+        waits through to the host work that caused it."""
         now = self._clock()
-        if self.policy is not None:
-            self._enforce_queue_policy(now)
-            self._enforce_active_deadlines(now)
-        # FIFO admission into free slots
-        if self._admission is None:
-            for s in range(self.slots):
-                if self.slot_req[s] is None and self.queue:
-                    if self.policy is None:
-                        self._admit(s, self.queue.popleft())
-                    else:
-                        req = self._next_admittable(now)
-                        if req is None:
-                            break
-                        self._admit(s, req)
-        else:
-            self._admit_pipeline(now)
+        with self._tracer.span("tick", track="engine", tick=self._ticks,
+                               active=sum(r is not None
+                                          for r in self.slot_req),
+                               queued=len(self.queue)):
+            return self._tick(now)
+
+    def _admit_phase(self, now: float) -> None:
+        """Queue policy, slot fill and every admission call of the tick."""
+        st = self.stats
+        admitted, calls = st.c_admitted.value, st.c_admit_calls.value
+        with self._tracer.span("admit", track="engine") as sp:
+            if self.policy is not None:
+                self._enforce_queue_policy(now)
+                self._enforce_active_deadlines(now)
+            # FIFO admission into free slots
+            if self._admission is None:
+                for s in range(self.slots):
+                    if self.slot_req[s] is None and self.queue:
+                        if self.policy is None:
+                            self._admit(s, self.queue.popleft())
+                        else:
+                            req = self._next_admittable(now)
+                            if req is None:
+                                break
+                            self._admit(s, req)
+            else:
+                self._admit_pipeline(now)
+            sp.set_metadata(requests=int(st.c_admitted.value - admitted),
+                            calls=int(st.c_admit_calls.value - calls))
+
+    def _tick(self, now: float) -> int:
+        wl = self.workload
+        self._admit_phase(now)
         if self.guards is not None and self.guards.scrub_every > 0 \
                 and self._ticks and self._ticks % self.guards.scrub_every == 0:
             self._scrub("periodic")
@@ -717,25 +757,40 @@ class ServeCore:
             self._ticks += 1
             self.stats.c_dropped_ticks.inc()
             return len(active)
-        self._key, sub = jax.random.split(self._key)
-        with self._tracer.span(f"{wl.step_span}_tick", track="engine",
-                               tick=self._ticks, active=len(active),
-                               queued=len(self.queue)):
-            if self.guards is not None:
-                nxt, self.state, ok = self._step(
-                    self.params, self.state, jnp.asarray(self._feed),
-                    jnp.asarray(mask), sub, self._degree,
-                    jnp.asarray(self._fault_vec))
-                ok = np.asarray(ok)
-                self._fault_vec[:] = 0.0
-            else:
-                nxt, self.state = self._step(self.params, self.state,
-                                             jnp.asarray(self._feed),
-                                             jnp.asarray(mask), sub,
-                                             self._degree)
-                ok = None
-            nxt = np.asarray(nxt)
+        tracer = self._tracer
+        with tracer.span(f"{wl.step_span}_tick", track="engine",
+                         tick=self._ticks, active=len(active),
+                         queued=len(self.queue)):
+            with tracer.span("dispatch", track="engine"):
+                self._key, sub = jax.random.split(self._key)
+                if self.guards is not None:
+                    nxt, self.state, ok = self._step(
+                        self.params, self.state, jnp.asarray(self._feed),
+                        jnp.asarray(mask), sub, self._degree,
+                        jnp.asarray(self._fault_vec))
+                else:
+                    nxt, self.state = self._step(self.params, self.state,
+                                                 jnp.asarray(self._feed),
+                                                 jnp.asarray(mask), sub,
+                                                 self._degree)
+                    ok = None
+            with tracer.span("sync", track="engine"):
+                nxt = np.asarray(nxt)
+                if ok is not None:
+                    ok = np.asarray(ok)
+                    # cleared only once the step is done: the device may
+                    # read the host buffer until then
+                    self._fault_vec[:] = 0.0
         self._ticks += 1
+        with tracer.span("harvest", track="engine") as sp:
+            emitted, finished = self._harvest(active, nxt, ok)
+            sp.set_metadata(emitted=emitted, finished=finished)
+        return len(active)
+
+    def _harvest(self, active: list, nxt: np.ndarray, ok) -> tuple[int, int]:
+        """Bank the step's emissions: route and slot counters, per-slot
+        harvest, the emitter, completions.  Returns (emitted, finished)."""
+        wl = self.workload
         self.stats.c_steps.inc()
         self.stats.c_step_units.inc(len(active))
         for site in wl.step_sites:
@@ -743,6 +798,7 @@ class ServeCore:
         self._tracer.counter("slots", track="engine", active=len(active),
                              queued=len(self.queue))
         now = self._clock()
+        n_emitted = n_finished = 0
         for s in active:
             req = self.slot_req[s]
             if ok is not None and not ok[s]:
@@ -751,6 +807,7 @@ class ServeCore:
                 continue
             emitted, finished, info = wl.harvest(req, self._feed, s, nxt[s])
             if emitted:
+                n_emitted += 1
                 # a suppressed emission (e.g. an LM stop id) is neither
                 # banked nor charged against the budget; a request that
                 # finishes before emitting anything keeps t_first_emit == 0
@@ -767,6 +824,7 @@ class ServeCore:
                     self.emitter.push(req, req.out[-1])
                 self.slot_budget[s] -= 1
             if finished or self.slot_budget[s] <= 0:
+                n_finished += 1
                 req.done = True
                 req.t_done = now
                 self.done.append(req)
@@ -776,7 +834,7 @@ class ServeCore:
                                    rid=req.rid, slot=s,
                                    e2e_ms=round(req.e2e * 1e3, 3),
                                    **wl.done_args(req, info))
-        return len(active)
+        return n_emitted, n_finished
 
     def run_until_drained(self, max_ticks: int = 10_000) -> list[Request]:
         """Tick until the queue and every slot are empty (or ``max_ticks``);

@@ -227,6 +227,7 @@ class LMAdapter(ServableModel):
             ingested = 0
         feed[slot, 0] = int(prompt[-1])
         req.cursor = ingested
+        self.last_admit_shape = (ingested, ingested)
         return cache, ingested
 
     # ---- bucketed / packed / chunked admission ------------------------
@@ -243,11 +244,13 @@ class LMAdapter(ServableModel):
         B = feed.shape[0]
         ingested = {}
         bucketed = []
+        bucket = padded = 0
         for slot, req in pairs:
             n = req.payload_units - 1
             if n > a.buckets[-1]:
                 cache, ingested[id(req)] = self.admit(params, cache, feed,
                                                       slot, req, degree)
+                bucket, padded = max(bucket, n), padded + n
             else:
                 bucketed.append((slot, req))
         for i in range(0, len(bucketed), a.pack):
@@ -267,7 +270,8 @@ class LMAdapter(ServableModel):
             cache = self._prefill_batch(params, cache, jnp.asarray(toks),
                                         jnp.asarray(slots),
                                         jnp.asarray(lengths), degree)
-            self.last_admit_bucket = Pb
+            bucket, padded = max(bucket, Pb), padded + a.pack * Pb
+        self.last_admit_shape = (bucket, padded)
         return cache, [ingested[id(r)] for _, r in pairs]
 
     def admit_chunk(self, params, cache, feed, slot, req, degree):
@@ -290,6 +294,7 @@ class LMAdapter(ServableModel):
         req.cursor += take
         if req.cursor >= target:
             feed[slot, 0] = int(prompt[-1])
+        self.last_admit_shape = (C, C)
         return cache, take
 
     def admit_complete(self, req) -> bool:

@@ -75,6 +75,13 @@ class ServableModel:
     #: reads this to drive bucketed/packed/chunked admission
     admission = None
 
+    #: ``(bucket, padded)`` of the last admission call, set by the workload
+    #: as it returns: the payload length its executable was built for, and
+    #: the positions it computed over all its rows, padding included (the
+    #: engine's admit-span args and bucket counter); None where the
+    #: workload does not say, and the engine takes the units ingested
+    last_admit_shape: Optional[tuple] = None
+
     # ---- weights ------------------------------------------------------
     def prepack(self, params):
         """Quantize-once residency hook (DESIGN.md §9); identity by default."""

@@ -75,8 +75,8 @@ class TrainerConfig:
 
 class Trainer:
     """``registry`` / ``tracer`` (DESIGN.md §11): step/checkpoint spans and
-    QoS ladder events go to the process-global tracer by default (free when
-    disabled); counters/gauges land in a fresh per-trainer registry unless
+    QoS ladder events go to the process-global tracer by default (nearly
+    free when disabled); counters/gauges land in a fresh per-trainer registry unless
     a shared one is passed (``launch.train --metrics-out`` exports it)."""
 
     def __init__(self, model: Model, scfg: step_mod.StepConfig,

@@ -89,6 +89,32 @@ def test_axqmm_gated_packed_compiles(sds, m):
              sds((m, D_MODEL), jnp.float32), up, up, sds((), jnp.int32))
 
 
+def test_axqmm_ops_carry_their_name(sds):
+    """A profiler trace names a device op after its HLO instruction.  Inside
+    a scanned layer stack the AXQ calls keep their own names; unnamed, they
+    took the enclosing ``closed_call.N``."""
+    import re
+
+    def stack(x, up, gate, down, e):
+        def layer(h, w):
+            u, g, d = w
+            a = axqmm_gated_packed(h, u, g, e, interpret=False)
+            return axqmm_packed(a, d, e, residual=h, interpret=False), None
+        h, _ = jax.lax.scan(layer, x, (up, gate, down))
+        return h
+
+    L = 2
+    up = PackedQWeight(sds((L, D_FF, D_MODEL), jnp.int8),
+                       sds((L, D_FF, D_MODEL // BLOCK), jnp.float32))
+    down = PackedQWeight(sds((L, D_MODEL, D_FF), jnp.int8),
+                         sds((L, D_MODEL, D_FF // BLOCK), jnp.float32))
+    text = _compile(stack, sds((SLOTS, D_MODEL), jnp.float32), up, up, down,
+                    sds((), jnp.int32)).as_text()
+    names = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
+    assert sorted(re.sub(r"\.\d+$", "", n) for n in names) == [
+        "axqmm", "axqmm_gated"]
+
+
 def test_flash_attention_causal_compiles(sds):
     qkv = sds((HEADS, 256, HEAD_DIM), jnp.bfloat16)
     _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
