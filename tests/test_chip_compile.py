@@ -1,6 +1,7 @@
 """Compile the serving path's Pallas kernels for a TPU v5e that is described,
 not attached: at TinyLlama-1.1B widths (d_model 2048, d_ff 5632, 32 heads
-over 4 kv heads of 64, vocab 32000), with decode M equal to the slot count.
+over 4 kv heads of 64, vocab 32000), with decode M equal to the slot count,
+and the AXQ GEMMs again at Qwen2.5-3B widths at decode and prefill M.
 
 Interpret mode accepts block shapes and operand types the chip's compiler
 refuses; these compiles catch that here.  Each test checks that the
@@ -8,6 +9,7 @@ compiled program holds the Mosaic kernel (``tpu_custom_call``) and prints
 its memory analysis.  Nothing runs: results and times need the chip.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -93,8 +95,6 @@ def test_axqmm_ops_carry_their_name(sds):
     """A profiler trace names a device op after its HLO instruction.  Inside
     a scanned layer stack the AXQ calls keep their own names; unnamed, they
     took the enclosing ``closed_call.N``."""
-    import re
-
     def stack(x, up, gate, down, e):
         def layer(h, w):
             u, g, d = w
@@ -110,9 +110,67 @@ def test_axqmm_ops_carry_their_name(sds):
                          sds((L, D_MODEL, D_FF // BLOCK), jnp.float32))
     text = _compile(stack, sds((SLOTS, D_MODEL), jnp.float32), up, up, down,
                     sds((), jnp.int32)).as_text()
-    names = re.findall(r"%([\w.-]+) = \S+ custom-call\(", text)
-    assert sorted(re.sub(r"\.\d+$", "", n) for n in names) == [
-        "axqmm", "axqmm_gated"]
+    assert _custom_call_names(text) == ["axqmm", "axqmm_gated"]
+
+
+def _custom_call_names(text):
+    """The compiled program's Mosaic kernels by op name, numbering dropped
+    (the compiler's own custom calls, such as ``ConcatBitcast``, left out)."""
+    names = re.findall(r"%([\w.-]+) = \S+ custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    return sorted(re.sub(r"\.\d+$", "", n) for n in names)
+
+
+# Qwen2.5-3B widths: 36 layers, d_model 2048, d_ff 11008, 16 query and 2 kv
+# heads of 128, tied vocabulary 151936; decode M is the 16 slots, prefill
+# M = 2 x bucket (packing 2, buckets up to 1536)
+QWEN_D, QWEN_FF, QWEN_KV, QWEN_VOCAB, QWEN_LAYERS = 2048, 11008, 256, 151936, 36
+QWEN_SLOTS = 16
+
+
+def _grid_steps(M, N, K, n_w=1):
+    from repro.kernels.axqmm import axq_tiles
+
+    bm, bn, bk = axq_tiles(M, N, K, BLOCK, n_w)
+    return -(-M // bm) * -(-N // bn) * (K // bk)
+
+
+def test_qwen_decode_step_grid_steps():
+    """One Qwen2.5-3B decode step runs 6 AXQ calls a layer and the tied
+    unembedding; at 8 x 128 x 256 tiles that was ~139K grid steps."""
+    d, ff, kv, M = QWEN_D, QWEN_FF, QWEN_KV, QWEN_SLOTS
+    layer = (2 * _grid_steps(M, d, d) + 2 * _grid_steps(M, kv, d)
+             + _grid_steps(M, ff, d, n_w=2) + _grid_steps(M, d, ff))
+    total = QWEN_LAYERS * layer + _grid_steps(M, QWEN_VOCAB, d)
+    print("grid steps per Qwen2.5-3B decode step:", total)
+    assert total < 10_000
+
+
+@pytest.mark.parametrize("m", [QWEN_SLOTS, 256, 3072],
+                         ids=["decode", "prefill256", "prefill3072"])
+@pytest.mark.parametrize("k,n", [
+    (QWEN_D, QWEN_D),                   # wq / wo
+    (QWEN_D, QWEN_KV),                  # wk / wv
+    (QWEN_FF, QWEN_D),                  # mlp down
+    (QWEN_D, QWEN_VOCAB),               # tied unembedding
+], ids=["wq", "wk", "down", "unembed"])
+def test_axqmm_packed_compiles_at_qwen_widths(sds, m, k, n):
+    text = _compile(
+        lambda x, w, e, r: axqmm_packed(x, w, e, residual=r, interpret=False),
+        sds((m, k), jnp.float32), _packed(sds, n, k), sds((), jnp.int32),
+        sds((m, n), jnp.float32)).as_text()
+    assert _custom_call_names(text) == ["axqmm"]
+
+
+@pytest.mark.parametrize("m", [QWEN_SLOTS, 256, 3072],
+                         ids=["decode", "prefill256", "prefill3072"])
+def test_axqmm_gated_packed_compiles_at_qwen_widths(sds, m):
+    up = _packed(sds, QWEN_FF, QWEN_D)
+    text = _compile(lambda x, u, g, e: axqmm_gated_packed(x, u, g, e,
+                                                          interpret=False),
+                    sds((m, QWEN_D), jnp.float32), up, up,
+                    sds((), jnp.int32)).as_text()
+    assert _custom_call_names(text) == ["axqmm_gated"]
 
 
 def test_flash_attention_causal_compiles(sds):

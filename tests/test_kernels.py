@@ -53,6 +53,73 @@ def test_axqmm_dynamic_degree_single_executable():
     assert float(jnp.abs(y8 - exact).mean()) < float(jnp.abs(y4 - exact).mean())
 
 
+def _old_tiles(M, block):
+    """The tiling before tiles followed the call's shapes: rows 128 / 64 /
+    8, columns 128, one quantization block per k step."""
+    return (128 if M % 128 == 0 else 64 if M % 64 == 0 else 8), 128, block
+
+
+@pytest.mark.parametrize("e", [8, 5])
+@pytest.mark.parametrize("M", [16, 1024], ids=["decode", "prefill"])
+@pytest.mark.parametrize("kind", ["plain", "bias_residual", "gated"])
+def test_axqmm_shape_tiles_bit_identical_to_old_tiling(kind, M, e):
+    """The shape-chosen tiles (one row block at decode, a k tile over all
+    five quantization blocks, a ragged last column block: N / 128 = 11)
+    give the same bits as the old tiling through the same kernel."""
+    from repro.kernels import axqmm as axq
+    from repro.kernels.qstore import prepack_weight
+
+    K, N, block = 5 * 256, 11 * 128, 256
+    k = jax.random.PRNGKey(M + e)
+    x = jax.random.normal(k, (M, K), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(k, 1), (K, N), jnp.float32)
+    pw = prepack_weight(w, block)
+    qx, sx = axq.quantize_for_axqmm(x, block)
+    old = _old_tiles(M, block)
+    if kind == "gated":
+        pg = prepack_weight(jax.random.normal(jax.random.fold_in(k, 2),
+                                              (K, N), jnp.float32), block)
+        new = axq.axqmm_gated_packed(x, pw, pg, e)
+        ref_ = axq._axqmm_gated_call(qx, sx, pw.qw, pw.scales, pg.qw,
+                                     pg.scales, e, act="silu", tiles=old,
+                                     interpret=True)
+        tiles = axq.axq_tiles(M, N, K, block, n_w=2)
+    else:
+        b = r = None
+        if kind == "bias_residual":
+            b = jax.random.normal(jax.random.fold_in(k, 3), (N,))
+            r = jax.random.normal(jax.random.fold_in(k, 4), (M, N))
+        new = axq.axqmm_packed(x, pw, e, bias=b, residual=r)
+        ref_ = axq._axqmm_call(qx, sx, pw.qw, pw.scales, e, b, r, tiles=old,
+                               interpret=True)
+        tiles = axq.axq_tiles(M, N, K, block)
+    assert tiles[2] == K and tiles[0] == min(M, axq._TILES[0])
+    assert N % tiles[1]
+    assert new.shape == (M, N)
+    np.testing.assert_array_equal(np.asarray(new), np.asarray(ref_))
+
+
+@pytest.mark.parametrize("M,N,K,n_w", [
+    (16, 2048, 2048, 1), (16, 11008, 2048, 2), (16, 2048, 11008, 1),
+    (16, 151936, 2048, 1), (3072, 2048, 11008, 1), (3072, 11008, 2048, 2),
+    (600, 96, 256, 1), (16, 128, 1 << 17, 1)])
+def test_axq_tiles_follow_the_shapes(M, N, K, n_w):
+    """Legal blocks (whole dims, or multiples of 32 rows and 128 columns),
+    a k tile of whole quantization blocks dividing K, the VMEM budget kept
+    wherever some tiling keeps it, and one row block up to the largest
+    row tile."""
+    from repro.kernels import axqmm as axq
+
+    bm, bn, bk = axq.axq_tiles(M, N, K, 256, n_w)
+    top = axq._TILES[0]
+    assert bm == M if M <= top else bm % 32 == 0
+    assert bn == N if N <= top else bn % 128 == 0
+    assert bk % 256 == 0 and K % bk == 0
+    assert axq._vmem_bytes(bm, bn, bk, K, 256, n_w) <= axq._VMEM_BUDGET
+    if K <= 11008:
+        assert bk == K
+
+
 @pytest.mark.parametrize("p,r", [(0, 0), (1, 2), (2, 4), (4, 8)])
 def test_pr_multiply_kernel_bit_exact(p, r):
     rng = np.random.default_rng(p * 10 + r)
