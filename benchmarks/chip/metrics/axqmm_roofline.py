@@ -2,8 +2,8 @@
 their active rows with the unembedding, prefill calls at their real prompt
 tokens), over the summed device time of the ``axqmm`` kernels, in %."""
 
+import spec
 import tracereduce as tr
-import workcount as wc
 
 KERNEL = r"^axqmm$"
 
@@ -12,8 +12,9 @@ def read(ctx):
     ns, n = tr.time_matching(ctx.kernels, KERNEL)
     if not n:
         return None
-    least = sum(wc.axq_call_least_s(ctx.arch, len(pos), ctx.peaks, True)
+    fam = spec.family(ctx.arch["family"])
+    least = sum(fam.axq_call_least_s(ctx.arch, len(pos), ctx.peaks, True)
                 for pos in ctx.work.steps)
-    least += sum(wc.axq_call_least_s(ctx.arch, sum(call), ctx.peaks, False)
+    least += sum(fam.axq_call_least_s(ctx.arch, sum(call), ctx.peaks, False)
                  for call in ctx.work.prefills)
     return 100.0 * least / (ns / 1e9)

@@ -6,14 +6,18 @@
 A cell is ``<config>.<traffic>`` in ``BENCHMARK.json`` (see ``spec.py``).
 A run, in order: points JAX's persistent compilation cache at the
 checkout (``repro.launch.compile_cache``) and lets it keep every
-executable; makes the cell's weights on the device from the seed; prepacks
-them for the configuration's policy; builds ``repro.serve.lm.ServeEngine``
-as ``repro.launch.serve`` does, with the traffic's bucket ladder and
-packing, which warms those buckets and the fused decode step; serves one
-short request to warm the tick path; then drives the open-loop schedule
-for ``--seconds`` (``serveloop.py``).  After the window it reads the
-window's peak of device memory, frees the engine, and compares a sample of
-the served requests with the plain float32 reference (``refmodel.py``).
+executable; makes the cell's weights from the seed through its model
+family (``families/<arch.family>.py``), each leaf on the chip that the
+program keeps it on, over a ``(1, chips)`` mesh; prepacks them for the
+configuration's policy; builds ``repro.serve.lm.ServeEngine`` (one chip)
+or ``repro.serve.sharded.ShardedServeEngine`` (more) as
+``repro.launch.serve`` does, with the traffic's bucket ladder and packing,
+which warms those buckets and the fused decode step; serves one short
+request to warm the tick path; then drives the open-loop schedule for
+``--seconds`` (``serveloop.py``).  After the window it reads the window's
+peak of device memory on the fullest chip, frees the engine, and compares
+a sample of the served requests with the family's plain float32
+reference, on the same mesh.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
@@ -87,26 +91,36 @@ def device_info() -> dict:
 # ---------------------------------------------------------------------------
 
 
+def mesh_of(cell: spec.Cell):
+    """The cell's ``(1, chips)`` mesh over ``("data", "model")``."""
+    from repro.dist import meshctx
+
+    return meshctx.make_mesh((1, cell.chips), ("data", "model"))
+
+
 def build(cell: spec.Cell, seed: int, *, approx: str | None = None,
           parts: dict):
-    """The cell's engine, warmed, on weights made from ``seed``."""
+    """The cell's engine, warmed, on weights made from ``seed``: on one
+    chip ``ServeEngine``, on more ``ShardedServeEngine`` with exact
+    collectives (no int8 ring) over the mesh's ``model`` axis."""
     import jax
 
-    import weights
     from repro.configs.base import ArchConfig
     from repro.core.approx import policy_from_flag
     from repro.dist import meshctx
     from repro.models import build_model
     from repro.serve.admission import AdmissionConfig
     from repro.serve.engine import ServeEngine
+    from repro.serve.sharded import ShardedServeEngine
 
     arch = cell.config["arch"]
+    fam = cell.family
     tr = cell.traffic
-    meshctx.set_mesh(meshctx.make_mesh((1, 1), ("data", "model")))
+    mesh = meshctx.set_mesh(mesh_of(cell))
     model = build_model(ArchConfig(**arch),
                         policy_from_flag(approx or cell.config["approx"]))
     t = time.time()
-    params = weights.to_program(weights.make(arch, seed), arch)
+    params = fam.to_program(fam.make(arch, seed, mesh), arch)
     jax.block_until_ready(params)
     parts["weights_s"] = time.time() - t
     t = time.time()
@@ -114,16 +128,27 @@ def build(cell: spec.Cell, seed: int, *, approx: str | None = None,
     jax.block_until_ready(params)
     parts["prepack_s"] = time.time() - t
     t = time.time()
-    eng = ServeEngine(
-        model, params, slots=tr["slots"], max_len=tr["max_len"], tp=1,
-        eos_id=-1, greedy=True, temperature=1e-6, top_k=0,
-        seed=int(seed) % (2 ** 31), qos=None, prepack=False,
+    common = dict(
+        slots=tr["slots"], max_len=tr["max_len"], eos_id=-1, greedy=True,
+        temperature=1e-6, top_k=0, seed=int(seed) % (2 ** 31), qos=None,
+        prepack=False,
         admission=AdmissionConfig(buckets=tuple(tr["buckets"]),
                                   pack=tr["pack"]))
+    if cell.chips == 1:
+        eng = ServeEngine(model, params, tp=1, **common)
+    else:
+        eng = ShardedServeEngine(model, params, mesh=mesh, ring=False,
+                                 **common)
     parts["engine_warmup_s"] = time.time() - t
     t = time.time()
-    eng.submit(np.arange(1, 9, dtype=np.int32), 2)
-    eng.run_until_drained()
+    # one short request warms the tick path; on several chips the first
+    # live prefill of each bucket traces again after the engine's own
+    # warm-up (PERF.md, open questions), so one prompt per bucket goes
+    # through the live path here, in set-up
+    warm = [8] + ([b + 1 for b in tr["buckets"]] if cell.chips > 1 else [])
+    for n in warm:
+        eng.submit(np.arange(1, n + 1, dtype=np.int32), 2)
+        eng.run_until_drained()
     eng.done.clear()
     parts["first_request_s"] = time.time() - t
     return eng
@@ -171,24 +196,22 @@ def compare(cell: spec.Cell, seed: int, chosen: list) -> dict:
     loop weigh as one request, not as every token it repeats."""
     import jax
 
-    import refmodel
-    import weights
-
     arch = cell.config["arch"]
-    w = weights.make(arch, seed)
+    fam = cell.family
+    w = fam.make(arch, seed, mesh_of(cell))
     worst, total, tokens, short, per_request = 0.0, 0.0, 0, 0, []
     for r in chosen:
         out = list(r.handle.out)
         short += int(len(out) != r.out_len)
         seq = np.concatenate([r.prompt, np.asarray(out[:-1], np.int32)])
-        S = refmodel.padded_len(len(seq))
+        S = fam.padded_len(len(seq))
         toks = np.zeros(S, np.int32)
         toks[:len(seq)] = seq
         tgt = np.full(S, -1, np.int32)
         P = len(r.prompt)
         tgt[P - 1:P - 1 + len(out)] = out
-        g = np.asarray(refmodel.gaps(w, arch, jax.numpy.asarray(toks),
-                                     jax.numpy.asarray(tgt)))
+        g = np.asarray(fam.gaps(w, arch, jax.numpy.asarray(toks),
+                            jax.numpy.asarray(tgt)))
         worst = max(worst, float(g.max()))
         total += float(g.sum())
         tokens += len(out)
@@ -229,11 +252,14 @@ def load_reader(name: str):
 
 
 class LayerCtx:
-    """What a per-layer metric reader gets: the traced stretch of chip 0
-    and what the loop dispatched in it."""
+    """What a per-layer metric reader gets: the traced stretch of chip 0,
+    what the loop dispatched in it, and the peaks of the cell's chips
+    taken together (``workcount.cell_peaks``), against which the whole
+    cell's counts give chip 0's share."""
 
     def __init__(self, trace, work, cell: spec.Cell, peaks: dict):
         import tracereduce as tr
+        import workcount as wc
 
         spans = [s for s in trace.spans if s[0] == "bench.traced"]
         if not spans:
@@ -246,7 +272,7 @@ class LayerCtx:
         self.work = work
         self.arch = cell.config["arch"]
         self.config = cell.config
-        self.peaks = peaks
+        self.peaks = wc.cell_peaks(peaks, cell.chips)
 
 
 def reduce_trace(trace_dir: str, work, cell: spec.Cell, peaks: dict):
@@ -288,7 +314,8 @@ def window_peak(setup_peak: int, process_peak: int, tick_max: int) -> int:
 
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
              approx: str | None = None, rate_rps: float | None = None,
-             t_start: float = T_START, peaks: dict | None = None) -> dict:
+             t_start: float = T_START,
+             peaks: dict | None = None) -> dict:
     import jax
 
     import serveloop

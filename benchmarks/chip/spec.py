@@ -8,18 +8,45 @@ mix, cell or per-layer metric is a new file and never an edit:
   traffic/<traffic>.json   arrival and length parameters, engine slots,
                            cache length, prefill bucket ladder and packing
   cells/<cell>.json        the cell's offered rate and its correctness limit
+  families/<family>.py     what depends on the model family, found by the
+                           configuration's ``arch.family`` (see below)
   metrics/<metric>.py      the reader of one per-layer metric
   peaks.json               the chip's published peaks, by device kind
+
+A family module provides, for configurations that name it:
+
+  make(arch, seed, mesh)   the weights in the family's own layout, drawn
+                           from the seed in one jitted call, each leaf
+                           placed on ``mesh`` as the program places it
+                           (``weights.placed``)
+  to_program(w, arch)      the same arrays in the program's parameter tree
+  gaps(w, arch, tokens, targets), padded_len(n)
+                           the plain float32 reference: per position, its
+                           best logit minus its logit of the served token,
+                           under ``highest`` matmul precision, with no
+                           kernels, cache or batching
+  projections, attended, prefill_flops, decode_flops, axq_call_least_s,
+  decode_attn_least_s      the work counts of the whole cell
+                           (``workcount.py``)
+
+The cell's ``chips`` in ``BENCHMARK.json`` sets the mesh, ``(1, chips)``
+over ``("data", "model")``, and the engine: ``ServeEngine`` on one chip,
+``ShardedServeEngine`` with exact collectives over the ``model`` axis on
+more.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+#: where ``family`` looks for ``<family>.py``, in order
+FAMILY_DIRS = (HERE / "families",)
+_FAMILIES: dict = {}
 
 
 def load_json(path: Path) -> dict:
@@ -43,6 +70,29 @@ class Cell:
     @property
     def rate_rps(self) -> float:
         return float(self.cell["rate_rps"])
+
+    @property
+    def family(self):
+        return family(self.config["arch"]["family"])
+
+
+def family(name: str):
+    """The module of the model family ``name`` (a configuration's
+    ``arch.family``): ``<name>.py`` in the first of FAMILY_DIRS that holds
+    one, loaded once."""
+    path = next((d / f"{name}.py" for d in FAMILY_DIRS
+                 if (d / f"{name}.py").is_file()), None)
+    if path is None:
+        raise KeyError(f"no family module {name}.py in "
+                       f"{[str(d) for d in FAMILY_DIRS]}")
+    mod = _FAMILIES.get(path)
+    if mod is None:
+        mod_spec = importlib.util.spec_from_file_location(f"family_{name}",
+                                                          path)
+        mod = importlib.util.module_from_spec(mod_spec)
+        mod_spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return mod
 
 
 def _reported(metrics: list, cell: str, e2e_names=None) -> list:

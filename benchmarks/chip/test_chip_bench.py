@@ -30,6 +30,7 @@ import tracereduce as tr  # noqa: E402
 import workcount as wc  # noqa: E402
 
 BENCH = spec.benchmark()
+DENSE = spec.family("dense")
 CELLS = [w["name"] for w in BENCH["workloads"]]
 SEEDS = (0, 1, 2 ** 31 + 11, 3_000_000_017)
 
@@ -186,18 +187,18 @@ PEAKS = {"bf16_flops_per_s": 100.0, "int8_ops_per_s": 200.0,
 
 def test_layer_params_and_flops():
     # q 8x8, k 8x4, v 8x4, o 8x8, gate/up 8x16, down 16x8
-    assert wc.layer_params(ARCH) == 64 + 32 + 32 + 64 + 128 * 3
+    assert DENSE.layer_params(ARCH) == 64 + 32 + 32 + 64 + 128 * 3
     # one token at index 0: matmuls + attention over 1 position
-    assert wc.prefill_flops(ARCH, 1) == 2 * (2 * 576 + 4 * 2 * 4 * 1)
+    assert DENSE.prefill_flops(ARCH, 1) == 2 * (2 * 576 + 4 * 2 * 4 * 1)
     # 3 tokens attend 1 + 2 + 3 positions
-    assert wc.prefill_flops(ARCH, 3) == 2 * (2 * 3 * 576 + 32 * 6)
+    assert DENSE.prefill_flops(ARCH, 3) == 2 * (2 * 3 * 576 + 32 * 6)
     # one slot decoding index 4 (5 positions), plus the unembedding
-    assert wc.decode_flops(ARCH, [4]) == 2 * 2 * 576 + 2 * 8 * 10 + 2 * 32 * 5
+    assert DENSE.decode_flops(ARCH, [4]) == 2 * 2 * 576 + 2 * 8 * 10 + 2 * 32 * 5
 
 
 def test_window_caps_attended_positions():
     a = dict(ARCH, swa_window=3)
-    assert [wc.attended(a, p) for p in range(5)] == [1, 2, 3, 3, 3]
+    assert [DENSE.attended(a, p) for p in range(5)] == [1, 2, 3, 3, 3]
 
 
 def test_gemm_least_time():
@@ -218,7 +219,7 @@ def test_gemm_least_time():
 def test_decode_attention_least_time():
     # 2 slots at indices 1 and 2 attend 2 + 3 = 5 positions: k and v of 1
     # head of 4 dims in bf16, q in bf16 and out in f32 for 2 heads each
-    t = wc.decode_attn_least_s(ARCH, [1, 2], PEAKS, "bfloat16")
+    t = DENSE.decode_attn_least_s(ARCH, [1, 2], PEAKS, "bfloat16")
     bytes_ = 2 * 5 * 1 * 4 * 2 + 2 * 2 * 4 * 6
     assert t == pytest.approx(max(4 * 2 * 4 * 5 / 100, bytes_ / 10))
 
@@ -376,3 +377,36 @@ def test_altered_token_fails(monkeypatch):
     res = tiny_run("axq8")
     assert not res["correct"]
     assert res["compared"]["max_logit_gap"] > 1.0
+
+
+def _state_unchanged(step):
+    def broken(self, params, cache, feed, active, key, degree):
+        nxt, _ = step(self, params, cache, feed, active, key, degree)
+        return nxt, cache
+    return broken
+
+
+def _half_batch(step):
+    def broken(self, params, cache, feed, active, key, degree):
+        import jax.numpy as jnp
+
+        nxt, new = step(self, params, cache, feed, active, key, degree)
+        half = jnp.arange(nxt.shape[0]) < nxt.shape[0] // 2
+        return jnp.where(half.reshape((-1,) + (1,) * (nxt.ndim - 1)), nxt,
+                         jnp.zeros_like(nxt)), new
+    return broken
+
+
+@pytest.mark.parametrize("fault", [_state_unchanged, _half_batch],
+                         ids=["state_unchanged", "half_batch"])
+def test_broken_step_fails(monkeypatch, fault):
+    """The fused decode step returning its cache unchanged, or leaving the
+    upper half of the slots out (their token 0), fails the comparison."""
+    import repro.serve.engine  # noqa: F401  (serve.lm imports through it)
+    from repro.serve import lm
+
+    monkeypatch.setattr(lm.LMAdapter, "step", fault(lm.LMAdapter.step))
+    res = tiny_run("exact")
+    assert not res["correct"]
+    assert res["checks"]["request_mean_logit_gap"]["value"] > \
+        10 * TINY["exact"][1]

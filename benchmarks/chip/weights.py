@@ -1,27 +1,19 @@
-"""Random weights from the seed, made on the device in one jitted call.
+"""Random weights from the seed, made on the device in one jitted call, and
+placed on the cell's chips as the program places them.
 
 The benchmark owns the weights: the program under test is handed them in its
-own parameter layout (``to_program``), and the plain reference makes them
-again from the same seed after the program's state is freed.  Every leaf
-is drawn directly in the configuration's dtype; no float32 copy is made.
-
-Layout (layers stacked on a leading axis of ``n_layers``):
-  embed (V, d); unembed (d, V) unless tied; final_norm (d,)
-  layers: attn_norm, mlp_norm (L, d); wq (L, d, H*D); wk, wv (L, d, KV*D);
-          bq (L, H*D), bk, bv (L, KV*D) when the q/k/v projections have
-          biases; wo (L, H*D, d); w_gate, w_up (L, d, F); w_down (L, F, d)
+own parameter layout (the family's ``to_program``), and the plain reference
+makes them again from the same seed after the program's state is freed.
+Each family (``families/<family>.py``) lays out and draws its own leaves,
+directly in the configuration's dtype; this module holds what every family
+shares.
 """
 
 from __future__ import annotations
 
-import math
-
 import jax
-import jax.numpy as jnp
 import numpy as np
-
-BIAS_STD = 0.1
-NORM_STD = 0.05
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 def key_from_seed(seed: int):
@@ -31,74 +23,33 @@ def key_from_seed(seed: int):
                               int(words[1] >> 1))
 
 
-def shapes(arch: dict) -> dict:
-    L, d, H, KV, D, F, V = (arch[k] for k in (
-        "n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-        "vocab"))
-    layers = {
-        "attn_norm": (L, d), "mlp_norm": (L, d),
-        "wq": (L, d, H * D), "wk": (L, d, KV * D), "wv": (L, d, KV * D),
-        "wo": (L, H * D, d),
-        "w_gate": (L, d, F), "w_up": (L, d, F), "w_down": (L, F, d),
-    }
-    if arch["qkv_bias"]:
-        layers.update(bq=(L, H * D), bk=(L, KV * D), bv=(L, KV * D))
-    out = {"embed": (V, d), "final_norm": (d,), "layers": layers}
-    if not arch["tie_embeddings"]:
-        out["unembed"] = (d, V)
-    return out
+def shardings(abstract, to_program, mesh):
+    """The ``NamedSharding`` on ``mesh`` of each leaf of ``abstract`` (a
+    family's layout): the spec that the program's partition rules
+    (``repro.dist.sharding``) give the same array in the program's layout
+    (``to_program``)."""
+    from repro.dist import sharding
+
+    leaves, treedef = jax.tree_util.tree_flatten(abstract)
+    ids = to_program(jax.tree_util.tree_unflatten(treedef,
+                                                  list(range(len(leaves)))))
+    specs = sharding.partition_params(to_program(abstract))
+    by_id = dict(zip(jax.tree_util.tree_leaves(ids),
+                     jax.tree_util.tree_leaves(
+                         specs, is_leaf=lambda s: isinstance(s,
+                                                             PartitionSpec))))
+    return jax.tree_util.tree_unflatten(
+        treedef, [NamedSharding(mesh, by_id[i]) for i in range(len(leaves))])
 
 
-def _scale(name: str, shape: tuple) -> tuple[float, float]:
-    """(mean, std) of a leaf."""
-    if name.endswith("norm"):
-        return 1.0, NORM_STD
-    if name in ("bq", "bk", "bv"):
-        return 0.0, BIAS_STD
-    if name == "embed":
-        return 0.0, 1.0 / math.sqrt(shape[-1])
-    return 0.0, 1.0 / math.sqrt(shape[-2])        # fan-in of (.., K, N)
-
-
-def make(arch: dict, seed: int, dtype=jnp.bfloat16) -> dict:
-    """All leaves for ``arch`` from ``seed``, in ``dtype``, on the default
-    device, in one jitted call."""
-    tree = shapes(arch)
-    flat, treedef = jax.tree_util.tree_flatten_with_path(
-        tree, is_leaf=lambda x: isinstance(x, tuple))
-
-    def build(key):
-        leaves = []
-        for i, (path, shape) in enumerate(flat):
-            name = path[-1].key
-            mean, std = _scale(name, shape)
-            x = jax.random.normal(jax.random.fold_in(key, i), shape, dtype)
-            leaves.append(x * jnp.asarray(std, dtype) + jnp.asarray(mean, dtype))
-        return jax.tree_util.tree_unflatten(treedef, leaves)
-
-    return jax.jit(build)(key_from_seed(seed))
-
-
-def to_program(w: dict, arch: dict) -> dict:
-    """The same arrays in the parameter layout of
-    ``repro.models.transformer.init_lm`` (no copies)."""
-    ly = w["layers"]
-
-    def dense(name, bias=None):
-        p = {"w": ly[name]}
-        if bias is not None and bias in ly:
-            p["b"] = ly[bias]
-        return p
-
-    layers = {
-        "ln1": {"scale": ly["attn_norm"]}, "ln2": {"scale": ly["mlp_norm"]},
-        "wq": dense("wq", "bq"), "wk": dense("wk", "bk"),
-        "wv": dense("wv", "bv"), "wo": dense("wo"),
-        "mlp": {"up": dense("w_up"), "gate": dense("w_gate"),
-                "down": dense("w_down")},
-    }
-    out = {"embed": {"emb": w["embed"]}, "layers": layers,
-           "ln_f": {"scale": w["final_norm"]}}
-    if not arch["tie_embeddings"]:
-        out["unembed"] = {"w": w["unembed"]}
-    return out
+def placed(build, key, to_program, mesh):
+    """``jax.jit(build)(key)`` with every leaf made where the program keeps
+    it (``shardings``), so no chip ever holds the whole model.  The values
+    do not depend on the placement (JAX's partitionable threefry).  On a
+    mesh of one chip every leaf goes to the default device, where the
+    one-chip engine's own arrays are, so its executables see one
+    placement."""
+    if mesh.size == 1:
+        return jax.jit(build)(key)
+    out = shardings(jax.eval_shape(build, key), to_program, mesh)
+    return jax.jit(build, out_shardings=out)(key)
